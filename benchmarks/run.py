@@ -1146,6 +1146,8 @@ def main(argv: list[str] | None = None) -> None:
     if unknown:
         raise SystemExit(f"unknown bench(es) {unknown}; "
                          f"choose from {sorted(_ALL_BENCHES)}")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in _ALL_BENCHES.items():
         if not names or name in names:

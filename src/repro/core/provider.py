@@ -9,8 +9,8 @@ FlockMTL calls OpenAI/Azure/Ollama over HTTP; FlockJAX's providers are:
                        served through repro.serving; random weights unless a
                        checkpoint is supplied, so outputs are structurally
                        real (true prefill/decode) but not semantically
-                       meaningful.  This is the provider the TPU dry-run
-                       configuration targets.
+                       meaningful.  This is the provider that runs on
+                       the chip (``chip_smoke.py``).
 
 Providers enforce the context window: requests above it raise
 ContextOverflowError, which drives the adaptive batcher's 10% backoff.
@@ -210,14 +210,15 @@ class LocalJaxProvider(BaseProvider):
     """
 
     def __init__(self, arch: str = "olmo-1b", *, use_smoke_config=True,
-                 checkpoint: Optional[str] = None, max_context: int = 2048):
+                 checkpoint: Optional[str] = None, max_context: int = 2048,
+                 seed: int = 0):
         super().__init__()
         from repro.configs import get_config, get_smoke_config
         from repro.serving.engine import ServingEngine
         cfg = (get_smoke_config(arch) if use_smoke_config
                else get_config(arch))
         self.engine = ServingEngine(cfg, checkpoint=checkpoint,
-                                    max_context=max_context)
+                                    max_context=max_context, seed=seed)
         # the serving engine mutates shared decode state (slots, pos, KV
         # cache); scheduler worker threads must take turns.  Concurrency
         # for this provider comes from the engine's own continuous
@@ -233,11 +234,17 @@ class LocalJaxProvider(BaseProvider):
         return bytes(int(t) % 256 for t in toks).decode("latin1")
 
     def complete(self, model, mp, n_rows):
-        self._check_context(model, mp, n_rows)
         t0 = time.monotonic()
         vocab = self.engine.cfg.vocab_size
         prompt = self._tokenize(mp.text, vocab)
         max_new = min(model.max_output_tokens * max(n_rows, 1), 64)
+        # the context check counts this provider's own tokens (one per
+        # byte), not the planner's per-character estimate
+        window = min(model.context_window, self.engine.max_context)
+        if len(prompt) + max_new > window:
+            raise ContextOverflowError(
+                f"{len(prompt)} prompt + {max_new} output tokens > "
+                f"context window {window}")
         with self._engine_lock:
             toks = self.engine.generate(prompt, max_new_tokens=max_new)
         text = self._detokenize(toks)

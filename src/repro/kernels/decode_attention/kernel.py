@@ -18,12 +18,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 NEG = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, scale: float, n_s: int):
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                   acc_scr, *, scale: float, n_s: int, block_s: int,
+                   kv_len: int, window: int):
+    b = pl.program_id(0)
     si = pl.program_id(1)
 
     @pl.when(si == 0)
@@ -36,7 +40,12 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
     k = k_ref[0]                                        # (bs, hd)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=F32)  # (G, bs)
-    s = jnp.where(valid_ref[0][None, :], s, NEG)
+    pos = pos_ref[b]
+    k_pos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    mask = (k_pos <= pos) & (k_pos < kv_len)
+    if window:
+        mask &= pos - k_pos < window
+    s = jnp.where(mask, s, NEG)
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1))
     p = jnp.exp(s - m_new[:, None])
@@ -53,34 +62,45 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr,
         o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def decode_attention_flat(q, k_cache, v_cache, valid, *,
+def decode_attention_flat(q, k_cache, v_cache, pos, *,
                           scale: float | None = None, block_s: int = 512,
-                          interpret: bool = True):
-    """q: (BKH, G, hd); caches: (BKH, S, hd); valid: (BKH, S) bool.
+                          kv_len: int | None = None, window: int = 0,
+                          interpret=None):
+    """q: (BKH, G, hd); caches: (BKH, S, hd); pos: (BKH,) int32 position
+    of each row's query token.  Key ``t`` of a row is attended iff
+    ``t <= pos``, ``t < kv_len`` and, with a ``window``,
+    ``pos - t < window``.  The mask is built in the kernel from the
+    scalar-prefetched ``pos``, so no mask array is read from HBM.
 
-    Returns (BKH, G, hd).  S must be a multiple of block_s (ops.py pads and
-    extends ``valid`` with False).
+    Returns (BKH, G, hd).  S must be a multiple of block_s (ops.py pads
+    and passes the true length as ``kv_len``).
     """
     BKH, G, hd = q.shape
     S = k_cache.shape[1]
     n_s = S // block_s
     scale = scale if scale is not None else hd ** -0.5
-    kernel = functools.partial(_decode_kernel, scale=scale, n_s=n_s)
+    kv_len = S if kv_len is None else kv_len
+    kernel = functools.partial(_decode_kernel, scale=scale, n_s=n_s,
+                               block_s=block_s, kv_len=kv_len,
+                               window=window)
     return pl.pallas_call(
         kernel,
-        grid=(BKH, n_s),
-        in_specs=[
-            pl.BlockSpec((1, G, hd), lambda b, si: (b, 0, 0)),
-            pl.BlockSpec((1, block_s, hd), lambda b, si: (b, si, 0)),
-            pl.BlockSpec((1, block_s, hd), lambda b, si: (b, si, 0)),
-            pl.BlockSpec((1, block_s), lambda b, si: (b, si)),
-        ],
-        out_specs=pl.BlockSpec((1, G, hd), lambda b, si: (b, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BKH, n_s),
+            in_specs=[
+                pl.BlockSpec((1, G, hd), lambda b, si, pos: (b, 0, 0)),
+                pl.BlockSpec((1, block_s, hd),
+                             lambda b, si, pos: (b, si, 0)),
+                pl.BlockSpec((1, block_s, hd),
+                             lambda b, si, pos: (b, si, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, G, hd), lambda b, si, pos: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G,), F32),
+                pltpu.VMEM((G,), F32),
+                pltpu.VMEM((G, hd), F32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((BKH, G, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), F32),
-            pltpu.VMEM((G,), F32),
-            pltpu.VMEM((G, hd), F32),
-        ],
-        interpret=interpret,
-    )(q, k_cache, v_cache, valid)
+        interpret=resolve_interpret(interpret),
+    )(pos, q, k_cache, v_cache)

@@ -12,7 +12,7 @@ from .kernel import decode_attention_flat
 
 @partial(jax.jit, static_argnames=("window", "block_s", "interpret"))
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
-                     block_s: int = 512, interpret: bool = True):
+                     block_s: int = 512, interpret=None):
     """q: (B, 1, H, hd); caches: (B, S, KH, hd); pos scalar or (B,).
     Returns (B, 1, H, hd)."""
     B, _, H, hd = q.shape
@@ -20,12 +20,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     G = H // KH
     block_s = min(block_s, max(S, 8))
     pad = (-S) % block_s
-    pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
-    k_pos = jnp.arange(S + pad)
-    valid = k_pos[None, :] <= pos_b[:, None]
-    valid = valid & (k_pos[None, :] < S)
-    if window:
-        valid = valid & (pos_b[:, None] - k_pos[None, :] < window)
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     if pad:
         widths = ((0, 0), (0, pad), (0, 0), (0, 0))
         k_cache = jnp.pad(k_cache, widths)
@@ -33,7 +28,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     kf = k_cache.transpose(0, 2, 1, 3).reshape(B * KH, S + pad, hd)
     vf = v_cache.transpose(0, 2, 1, 3).reshape(B * KH, S + pad, hd)
     qf = q.reshape(B, KH, G, hd).reshape(B * KH, G, hd)
-    validf = jnp.repeat(valid, KH, axis=0)      # (B*KH, S+pad)
-    o = decode_attention_flat(qf, kf, vf, validf, block_s=block_s,
+    o = decode_attention_flat(qf, kf, vf, jnp.repeat(pos_b, KH),
+                              block_s=block_s, kv_len=S, window=window,
                               interpret=interpret)
     return o.reshape(B, 1, H, hd)

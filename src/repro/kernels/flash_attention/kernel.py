@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 NEG = -1e30
 
@@ -80,7 +82,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          scale: float | None = None, block_q: int = 128,
                          block_k: int = 128, kv_len: int | None = None,
-                         interpret: bool = True):
+                         interpret=None):
     """q: (BH, Sq, hd); k, v: (BKH, Sk, hd); BH % BKH == 0.
 
     Sq/Sk must be padded to block multiples by the caller (ops.py does it);
@@ -116,5 +118,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), F32),
             pltpu.VMEM((block_q, hd), F32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

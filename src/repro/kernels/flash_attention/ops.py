@@ -24,7 +24,7 @@ def _pad_to(x, axis, mult):
                                    "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret=None):
     """q: (B, Sq, H, hd); k, v: (B, Sk, KH, hd) -> (B, Sq, H, hd)."""
     B, Sq, H, hd = q.shape
     Sk, KH = k.shape[1], k.shape[2]

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 
 
@@ -41,7 +43,7 @@ def _lru_kernel(a_ref, b_ref, o_ref, h_scr, *, chunk: int):
 
 
 def rg_lru_flat(a, b, *, chunk: int = 128, block_d: int = 512,
-                interpret: bool = True):
+                interpret=None):
     """a, b: (B, S, di) -> h: (B, S, di); S % chunk == 0, di % block_d == 0."""
     B, S, di = a.shape
     kernel = functools.partial(_lru_kernel, chunk=chunk)
@@ -56,5 +58,5 @@ def rg_lru_flat(a, b, *, chunk: int = 128, block_d: int = 512,
                                lambda b_, d, c: (b_, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, di), a.dtype),
         scratch_shapes=[pltpu.VMEM((block_d,), F32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

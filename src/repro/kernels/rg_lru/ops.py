@@ -12,7 +12,7 @@ from .kernel import rg_lru_flat
 
 @partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
 def rg_lru(a, b, *, chunk: int = 128, block_d: int = 512,
-           interpret: bool = True):
+           interpret=None):
     """Diagonal recurrence h_t = a_t*h_{t-1} + b_t; a, b: (B, S, di).
 
     Padding uses a=1, b=0 (identity elements) so padded steps are no-ops.

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 F32 = jnp.float32
 
 
@@ -57,7 +59,7 @@ def _ssm_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, o_ref, h_scr,
 
 
 def ssm_scan_flat(x, dt, Bm, Cm, A_log, D, *, chunk: int = 128,
-                  block_d: int = 256, interpret: bool = True):
+                  block_d: int = 256, interpret=None):
     """x, dt: (B, S, di); Bm, Cm: (B, S, N); A_log: (di, N); D: (di,).
 
     Returns y: (B, S, di).  S % chunk == 0 and di % block_d == 0 (ops.py
@@ -83,5 +85,5 @@ def ssm_scan_flat(x, dt, Bm, Cm, A_log, D, *, chunk: int = 128,
                                lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, di), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, N), F32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, dt, Bm, Cm, A_log, D)

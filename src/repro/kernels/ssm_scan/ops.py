@@ -12,7 +12,7 @@ from .kernel import ssm_scan_flat
 
 @partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
 def ssm_scan(x, dt, Bm, Cm, A_log, D, *, chunk: int = 128,
-             block_d: int = 256, interpret: bool = True):
+             block_d: int = 256, interpret=None):
     B, S, di = x.shape
     chunk = min(chunk, max(S, 8))
     block_d = min(block_d, di)

@@ -15,21 +15,21 @@ F32 = jnp.float32
 @partial(jax.jit,
          static_argnames=("k", "block_n", "block_t", "interpret"))
 def topk_sim(corpus, queries, k: int, *, block_n: int = 64,
-             block_t: int = 128, interpret=None):
+             block_t=None, interpret=None):
     """Exact cosine top-k via block-max pruning.
 
     corpus: (N, D) (normalised inside); queries: (Q, D).
     Returns (scores (Q, k), indices (Q, k)), exact (see kernel.py proof).
     ``k`` is capped at N; an empty corpus returns empty (Q, 0) results.
-    ``interpret=None`` resolves per backend (compiled on TPU/GPU,
-    interpreter on CPU)."""
+    ``interpret=None`` resolves per backend (interpreter on the CPU
+    only)."""
     N, D = corpus.shape
     Q = queries.shape[0]
     k = min(k, N)
     if N == 0 or k == 0 or Q == 0:
         return (jnp.zeros((Q, min(k, N)), F32),
                 jnp.zeros((Q, min(k, N)), jnp.int32))
-    block_n = min(block_n, max(N, 8))
+    block_n = min(block_n, -(-N // 8) * 8)
     cn = corpus / jnp.maximum(
         jnp.linalg.norm(corpus, axis=-1, keepdims=True), 1e-9)
     qn = queries / jnp.maximum(
@@ -48,7 +48,8 @@ def topk_sim(corpus, queries, k: int, *, block_n: int = 64,
     row_idx = jnp.minimum(row_idx, N - 1)
     in_range = row_idx < N
     cand = jnp.take(cn, row_idx, axis=0)                  # (Q, kb*bn, D)
-    s = jnp.einsum("qd,qnd->qn", qn.astype(F32), cand.astype(F32))
+    s = jnp.einsum("qd,qnd->qn", qn.astype(F32), cand.astype(F32),
+                   precision=jax.lax.Precision.HIGHEST)
     s = jnp.where(in_range, s, -jnp.inf)
     # dedupe clipped duplicates (same row gathered twice scores twice —
     # mask all but the first occurrence)

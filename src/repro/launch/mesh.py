@@ -7,30 +7,24 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    # jax.sharding.AxisType only exists on newer jax; on older releases
-    # (<=0.4.x) every axis is implicitly Auto, so omit the argument.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh helper (elastic re-shard paths, tests)."""
+    """Arbitrary mesh helper (elastic re-shard paths, tests).  Every axis
+    is Auto: the partitioner places what the shardings leave open."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
-# TPU v5e-class hardware constants used by the roofline analysis
+# TPU v5e-class constants for the dry-run's compile-only roofline estimate
+# (launch/dryrun.py) only; nothing that runs on a chip reads them
 PEAK_FLOPS_BF16 = 197e12        # per chip
 HBM_BW = 819e9                  # bytes/s per chip
 ICI_BW = 50e9                   # bytes/s per link

@@ -16,6 +16,7 @@ import time
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import ServingEngine
 
 
@@ -28,10 +29,11 @@ def run(argv=None):
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=48)
     ap.add_argument("--max-new", type=int, default=16)
-    ap.add_argument("--max-context", type=int, default=256)
+    ap.add_argument("--max-context", type=int, default=2048)
     ap.add_argument("--semantic", action="store_true",
                     help="drive via the semantic-operator layer")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
@@ -39,7 +41,9 @@ def run(argv=None):
     if args.semantic:
         from repro.core import SemanticContext, llm_complete
         from repro.core.provider import LocalJaxProvider
-        ctx = SemanticContext(provider=LocalJaxProvider(args.arch))
+        ctx = SemanticContext(provider=LocalJaxProvider(
+            args.arch, use_smoke_config=args.smoke,
+            max_context=args.max_context))
         rows = [{"text": f"request {i} body " * 3}
                 for i in range(args.requests)]
         t0 = time.monotonic()

@@ -362,8 +362,7 @@ def self_attention_train(cfg: ModelConfig, p, x, kind: str, positions,
     window = cfg.window_size if kind in ("local", "swa") else 0
     if cfg.use_pallas:
         from repro.kernels.flash_attention.ops import flash_attention
-        o = flash_attention(q, k, v, causal=causal, window=window,
-                            interpret=jax.default_backend() != "tpu")
+        o = flash_attention(q, k, v, causal=causal, window=window)
     elif cfg.attn_impl == "blocked":
         o = blocked_attention(q, k, v, causal=causal, window=window,
                               block_q=cfg.attn_block_k,
@@ -432,8 +431,7 @@ def self_attention_decode(cfg: ModelConfig, p, x, kind: str, cache, pos,
     if cfg.use_pallas:
         from repro.kernels.decode_attention.ops import \
             decode_attention as decode_attention_pallas
-        o = decode_attention_pallas(q, k_use, v_use, pos_b, window=window,
-                                    interpret=jax.default_backend() != "tpu")
+        o = decode_attention_pallas(q, k_use, v_use, pos_b, window=window)
     else:
         o = decode_attention(q, k_use, v_use, pos, window=window)
     return attn_out(p, o, policy), new_cache
@@ -750,8 +748,7 @@ def _mamba_core(cfg, p, x_c, policy, h0=None, return_state=False):
         + p["dt_bias"])                                          # (B,S,di)
     if cfg.use_pallas and h0 is None and not return_state:
         from repro.kernels.ssm_scan.ops import ssm_scan
-        y = ssm_scan(x_c, dt.astype(x_c.dtype), Bm, Cm, p["A_log"], p["D"],
-                     interpret=jax.default_backend() != "tpu")
+        y = ssm_scan(x_c, dt.astype(x_c.dtype), Bm, Cm, p["A_log"], p["D"])
         return y, None
     if cfg.ssm_fuse == "chunk":
         y, h_last = fused_selective_scan(cfg, x_c, dt, Bm, Cm, p["A_log"],
@@ -843,7 +840,7 @@ def _rglru_core(cfg, p, x_c, h0=None, return_state=False):
     b = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-6)) * gated
     if cfg.use_pallas and h0 is None and not return_state:
         from repro.kernels.rg_lru.ops import rg_lru
-        h_all = rg_lru(a, b, interpret=jax.default_backend() != "tpu")
+        h_all = rg_lru(a, b)
         return h_all, None
     h_all, h_last = linear_scan(a, b, h0, chunk=cfg.scan_chunk,
                                 unroll=cfg.unroll_inner)

@@ -27,16 +27,16 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 F32 = jnp.float32
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _local_topk(corpus_rows, queries, k: int, row_offset):
     """Exact top-k of ``queries`` against a contiguous corpus slice."""
-    s = jnp.einsum("qd,nd->qn", queries, corpus_rows,
+    s = jnp.einsum("qd,nd->qn", queries, corpus_rows, precision=HIGHEST,
                    preferred_element_type=F32)
     top_s, top_i = jax.lax.top_k(s, k)
     return top_s, top_i + row_offset
@@ -56,7 +56,8 @@ def sharded_topk(corpus, queries, k: int):
     cn = corpus / jnp.maximum(
         jnp.linalg.norm(corpus, axis=-1, keepdims=True), 1e-9)
     k = min(k, N)
-    s = jnp.einsum("qd,nd->qn", qn.astype(F32), cn.astype(F32))
+    s = jnp.einsum("qd,nd->qn", qn.astype(F32), cn.astype(F32),
+                   precision=HIGHEST)
     top_s, top_i = jax.lax.top_k(s, k)
     return top_s, top_i
 
@@ -78,7 +79,7 @@ def _blocked_local_topk(c, qn, k: int, offset, n_global: int, block: int):
 
     def bmax(blk):
         cb, vb = blk                                  # (bn, D), (bn,)
-        s = jnp.einsum("qd,nd->qn", qn, cb,
+        s = jnp.einsum("qd,nd->qn", qn, cb, precision=HIGHEST,
                        preferred_element_type=F32)
         return jnp.where(vb[None, :], s, -jnp.inf).max(axis=1)
 
@@ -89,7 +90,7 @@ def _blocked_local_topk(c, qn, k: int, offset, n_global: int, block: int):
     row_idx = (top_blocks[:, :, None] * bn
                + jnp.arange(bn)[None, None, :]).reshape(Q, kb * bn)
     cand = jnp.take(cp, row_idx, axis=0)              # (Q, kb*bn, D)
-    s = jnp.einsum("qd,qnd->qn", qn, cand,
+    s = jnp.einsum("qd,qnd->qn", qn, cand, precision=HIGHEST,
                    preferred_element_type=F32)
     s = jnp.where(valid[row_idx], s, -jnp.inf)
     top_s, pos = jax.lax.top_k(s, k)
@@ -107,22 +108,24 @@ def _flat_axes(mesh: Mesh, corpus_axes) -> tuple:
 
 
 def make_sharded_topk(mesh: Mesh, k: int, *, corpus_axes=None,
-                      block: int = 2048):
+                      block: int = 2048, n_valid=None):
     """Bind the shard-mapped blocked scan: corpus rows over every mesh
-    axis, queries replicated, (Q, shards*k) candidate all-gather only."""
+    axis, queries replicated, (Q, shards*k) candidate all-gather only.
+    ``n_valid`` rows of the corpus are real (default: all of them); rows
+    past it are padding that a caller placed to even out the shards."""
     axes = _flat_axes(mesh, corpus_axes)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     nshards = math.prod(sizes[a] for a in axes)
 
     def fn(corpus, queries):
-        N, D = corpus.shape
+        N = corpus.shape[0] if n_valid is None else n_valid
         cn = corpus / jnp.maximum(
             jnp.linalg.norm(corpus, axis=-1, keepdims=True), 1e-9)
         qn = queries / jnp.maximum(
             jnp.linalg.norm(queries, axis=-1, keepdims=True), 1e-9)
         qn = qn.astype(F32)
         kk = min(k, N)
-        pad = (-N) % nshards
+        pad = (-cn.shape[0]) % nshards
         cp = jnp.pad(cn, ((0, pad), (0, 0))) if pad else cn
         rows_local = cp.shape[0] // nshards
         kl = min(kk, rows_local)
@@ -134,7 +137,7 @@ def make_sharded_topk(mesh: Mesh, k: int, *, corpus_axes=None,
             return _blocked_local_topk(c, q, kl, shard * rows_local, N,
                                        block)
 
-        cand_s, cand_i = shard_map(
+        cand_s, cand_i = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axes, None), P(None, None)),
             out_specs=(P(None, axes), P(None, axes)))(cp, qn)

@@ -5,16 +5,23 @@ corpus-side scan is a blocked matmul with a running top-k, sharded over the
 (data, model) mesh when a policy is supplied.
 
 ``VectorIndex`` is the materialised index behind the ``vector_topk`` /
-``hybrid_topk`` plan operators (``engine.retrieval_ops``).  Scan routing:
+``hybrid_topk`` plan operators (``engine.retrieval_ops``).  The
+normalised corpus is placed on the device once and kept there.  Scan
+routing:
 
-  * >1-device mesh active (enclosing ``with mesh:`` or ``mesh=``) — the
-    shard-mapped ``distributed.sharded_topk`` blocked scan; corpus rows
-    shard, queries replicate, only (Q, devices*k) candidates all-gather.
-  * single device, compiled backend (TPU/GPU) or a large corpus — the
-    ``kernels/topk_sim`` block-max Pallas kernel (compiled on
-    accelerators, interpreted on CPU where only big scans amortise the
-    interpreter overhead).
-  * otherwise — the ``cosine_topk`` jnp scan.
+  * >1-device mesh (``mesh=`` or an enclosing ``jax.set_mesh``) — the
+    shard-mapped ``distributed.make_sharded_topk`` blocked scan; corpus
+    rows shard over every mesh axis, each shard copied from the host
+    straight to its own device; queries replicate, and only
+    (Q, devices*k) candidates all-gather.
+  * single device, backend not the CPU — the ``kernels/topk_sim``
+    block-max Pallas kernel, compiled.
+  * CPU — the same kernel in interpret mode for corpora of at least
+    ``KERNEL_MIN_ROWS_CPU`` rows, the ``cosine_topk`` jnp scan below that.
+
+Every scan contracts at float32 precision (``Precision.HIGHEST``): the
+exact scan stays exact on the TPU, whose default matmul precision rounds
+float32 operands to bfloat16.
 
 ``topk_ann`` routes through a lazily built ``retrieval.ivf.IVFIndex``
 (the ``vector_topk(ann=...)`` plan option); ``nprobe >= nlist`` probes
@@ -30,7 +37,6 @@ next to the base instead of re-embedding the whole corpus.
 
 from __future__ import annotations
 
-import logging
 from typing import Optional, Sequence
 
 import jax
@@ -39,11 +45,9 @@ import numpy as np
 
 from .ivf import IVFIndex
 
-logger = logging.getLogger(__name__)
-
-# On CPU the Pallas kernel runs interpreted; its per-call overhead only
-# amortises over big corpora, so small scans keep the jnp path (which is
-# also what the equivalence tests pin bit-for-bit on CPU).
+# On the CPU the Pallas kernel runs interpreted; its per-call overhead
+# only amortises over big corpora, so small scans keep the jnp path (which
+# is also what the equivalence tests pin bit-for-bit on the CPU).
 KERNEL_MIN_ROWS_CPU = 32768
 DEFAULT_RECALL_TARGET = 0.95
 
@@ -72,6 +76,7 @@ def cosine_topk(corpus: jnp.ndarray, queries: jnp.ndarray, k: int,
         best_s, best_i = carry                       # (Q, k)
         blk_idx, cb = inp
         s = jnp.einsum("qd,nd->qn", qn, cb,
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
         idx = blk_idx * block + jnp.arange(block)
         s = jnp.where(idx[None, :] < N, s, -jnp.inf)
@@ -89,19 +94,12 @@ def cosine_topk(corpus: jnp.ndarray, queries: jnp.ndarray, k: int,
 
 
 def active_mesh():
-    """The physical mesh of an enclosing ``with mesh:`` block, or None.
+    """The mesh of an enclosing ``jax.set_mesh`` block, or None.
 
     A single-device mesh is reported as None — sharding the corpus over
     one device only adds dispatch overhead."""
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-    except (ImportError, AttributeError) as exc:
-        # pxla internals moved across jax releases; treat an unknown
-        # layout as "no mesh" rather than failing the scan
-        logger.debug("active_mesh probe failed: %s", exc)
-        return None
-    if mesh is None or mesh.empty or mesh.size <= 1:
+    mesh = jax.sharding.get_mesh()
+    if mesh.empty or mesh.size <= 1:
         return None
     return mesh
 
@@ -125,7 +123,8 @@ class VectorIndex:
         self.mesh = mesh
         self.use_kernel = use_kernel
         self._topk = jax.jit(cosine_topk, static_argnames=("k", "block"))
-        self._sharded = {}          # k -> bound sharded scan
+        self._sharded = {}          # (mesh, k) -> bound sharded scan
+        self._placed = None         # (mesh or None, device array)
         self._ivf: Optional[IVFIndex] = None
 
     @classmethod
@@ -139,8 +138,38 @@ class VectorIndex:
         key = (id(mesh), k)
         fn = self._sharded.get(key)
         if fn is None:
-            fn = self._sharded[key] = make_sharded_topk(mesh, k)
+            fn = self._sharded[key] = make_sharded_topk(
+                mesh, k, n_valid=len(self.vectors))
         return fn
+
+    def device_corpus(self, mesh=None) -> jax.Array:
+        """The normalised corpus on the device, placed on first use and
+        kept.  With a mesh, rows shard over every mesh axis and each
+        shard is copied from host memory straight to its own device (no
+        device ever stages the whole corpus); the row count pads with
+        zero rows to a multiple of the shard count, and the sharded scan
+        masks them out."""
+        if self._placed is not None and self._placed[0] is mesh:
+            return self._placed[1]
+        self._placed = None           # drop a placement on another mesh
+        if mesh is None:
+            arr = jax.device_put(self.vectors)
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            n, d = self.vectors.shape
+            n_pad = -(-n // mesh.size) * mesh.size
+
+            def shard(idx):
+                lo, hi, _ = idx[0].indices(n_pad)
+                out = np.zeros((hi - lo, d), np.float32)
+                out[:max(0, min(hi, n) - lo)] = self.vectors[lo:hi]
+                return out
+
+            arr = jax.make_array_from_callback(
+                (n_pad, d), NamedSharding(mesh, P(mesh.axis_names, None)),
+                shard)
+        self._placed = (mesh, arr)
+        return arr
 
     def _route_kernel(self) -> bool:
         if self.use_kernel is not None:
@@ -158,14 +187,12 @@ class VectorIndex:
         mesh = self.mesh if self.mesh is not None else active_mesh()
         if mesh is not None:
             fn = self._sharded_topk(mesh, use_k)
-            s, i = fn(jnp.asarray(self.vectors), jnp.asarray(q))
+            s, i = fn(self.device_corpus(mesh), jnp.asarray(q))
         elif self._route_kernel():
             from repro.kernels.topk_sim.ops import topk_sim
-            s, i = topk_sim(jnp.asarray(self.vectors), jnp.asarray(q),
-                            use_k)
+            s, i = topk_sim(self.device_corpus(), jnp.asarray(q), use_k)
         else:
-            s, i = self._topk(jnp.asarray(self.vectors), jnp.asarray(q),
-                              use_k)
+            s, i = self._topk(self.device_corpus(), jnp.asarray(q), use_k)
         return np.asarray(s), np.asarray(i)
 
     # ---- ANN -------------------------------------------------------------

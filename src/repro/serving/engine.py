@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.batching import ContextOverflowError
 from repro.models import model as M
 from repro.models.config import ModelConfig
 
@@ -78,6 +79,15 @@ class ServingEngine:
     # ------------------------------------------------------------------ API
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 32,
                eos_token: int = -1) -> Request:
+        """Queue a request.  One whose prompt plus ``max_new_tokens``
+        cannot fit ``max_context`` raises ``ContextOverflowError`` here:
+        the caller splits or drops it, the engine never answers it
+        with nothing."""
+        need = len(prompt) + max_new_tokens
+        if need > self.max_context:
+            raise ContextOverflowError(
+                f"{len(prompt)} prompt + {max_new_tokens} new tokens > "
+                f"max_context {self.max_context}")
         req = Request(rid=next(self._rid), prompt=list(prompt),
                       max_new_tokens=max_new_tokens, eos_token=eos_token)
         req.pending_prompt = len(req.prompt)
@@ -96,14 +106,40 @@ class ServingEngine:
             self.step()
             max_steps -= 1
 
+    def score(self, tokens: Sequence[int]) -> np.ndarray:
+        """Teacher-forced logits ``(len(tokens), vocab_size)`` of one
+        prompt through the engine's own compiled steps, split as a request
+        is: whole chunks through chunked prefill, the last ``<= chunk``
+        tokens one by one through the decode step.  Runs in slot 0 of a
+        fresh cache; the serving state is untouched."""
+        if len(tokens) > self.max_context:
+            raise ContextOverflowError(
+                f"{len(tokens)} tokens > max_context {self.max_context}")
+        cache = M.init_cache(self.cfg, self.n_slots, self.max_context)
+        pos = np.zeros(self.n_slots, np.int32)
+        n_full = max(len(tokens) - 1, 0) // self.chunk
+        out = []
+        for c in range(n_full):
+            toks = np.zeros((self.n_slots, self.chunk), np.int32)
+            toks[0] = tokens[c * self.chunk:(c + 1) * self.chunk]
+            logits, cache = self._extend(self.params, jnp.asarray(toks),
+                                         cache, jnp.asarray(pos))
+            out.append(np.asarray(logits[0]))
+            pos[0] += self.chunk
+        for t in tokens[n_full * self.chunk:]:
+            toks = np.zeros((self.n_slots, 1), np.int32)
+            toks[0, 0] = t
+            logits, cache = self._decode(self.params, jnp.asarray(toks),
+                                         cache, jnp.asarray(pos))
+            out.append(np.asarray(logits[0]))
+            pos[0] += 1
+        return np.concatenate(out)[:, :self.cfg.vocab_size]
+
     # ----------------------------------------------------------------- step
     def _admit(self):
         for slot in range(self.n_slots):
             if self.active[slot] is None and self.waiting:
                 req = self.waiting.pop(0)
-                if len(req.prompt) + req.max_new_tokens > self.max_context:
-                    req.finished = True      # reject: cannot fit
-                    continue
                 req.slot = slot
                 req.pos = 0
                 self.active[slot] = req

@@ -5,7 +5,8 @@ Covers:
   * elastic re-shard: checkpoint saved under mesh (2,4) restores and keeps
     training under mesh (4,2) with identical loss trajectory;
   * sharded corpus top-k: numerics match the single-device oracle and the
-    compiled HLO keeps the corpus sharded (no full all-gather of it).
+    compiled HLO keeps the corpus sharded (no full all-gather of it);
+    a ``VectorIndex`` over the mesh places one padded shard per device.
 """
 
 import json
@@ -120,8 +121,23 @@ SHARDED_TOPK = textwrap.dedent("""
     ok_idx = bool((np.asarray(i) == np.asarray(i_ref)).all())
     # the corpus itself must stay sharded: no 4096x32 f32 all-gather
     corpus_gathered = "f32[4096,32]{1,0} all-gather" in txt
+
+    # VectorIndex over a mesh: 4100 rows pad to 4104 = 8 x 513, each
+    # shard placed on its own device, padding rows never returned
+    from repro.retrieval import VectorIndex
+    vecs = rng.standard_normal((4100, 32)).astype(np.float32)
+    index = VectorIndex(vecs, mesh=mesh)
+    si, ii = index.topk(np.asarray(queries), 10)
+    s_ref2, i_ref2 = topk_sim_ref(jnp.asarray(vecs), queries, 10)
+    shards = index.device_corpus(mesh).addressable_shards
     print(json.dumps({"scores": ok_scores, "idx": ok_idx,
-                      "corpus_gathered": corpus_gathered}))
+                      "corpus_gathered": corpus_gathered,
+                      "index_idx": bool((ii == np.asarray(i_ref2)).all()),
+                      "index_scores": bool(np.allclose(
+                          si, np.asarray(s_ref2), atol=1e-5)),
+                      "shard_rows": sorted({sh.data.shape[0]
+                                            for sh in shards}),
+                      "shard_devices": len({sh.device for sh in shards})}))
 """)
 
 
@@ -149,3 +165,5 @@ def test_sharded_topk_matches_oracle_and_stays_sharded():
     rec = _run(SHARDED_TOPK)
     assert rec["scores"] and rec["idx"]
     assert not rec["corpus_gathered"], "corpus was all-gathered"
+    assert rec["index_idx"] and rec["index_scores"]
+    assert rec["shard_rows"] == [513] and rec["shard_devices"] == 8

@@ -123,7 +123,7 @@ def test_topk_sim_empty_corpus_and_queries(rng):
 
 
 def test_topk_sim_interpret_default_is_backend_aware():
-    from repro.kernels.topk_sim.kernel import resolve_interpret
+    from repro.kernels import resolve_interpret
     # explicit settings win; None resolves per backend (the CI host is
     # CPU-only, where no compiled Pallas lowering exists)
     assert resolve_interpret(True) is True

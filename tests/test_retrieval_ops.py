@@ -616,7 +616,7 @@ def test_vector_index_sharded_path_matches_oracle():
     np.testing.assert_allclose(s, s_ref, rtol=1e-5, atol=1e-6)
     # auto-detection ignores single-device meshes (sharding over one
     # device only adds dispatch overhead)
-    with mesh:
+    with jax.set_mesh(mesh):
         assert active_mesh() is None
 
 

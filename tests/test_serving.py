@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.core.batching import ContextOverflowError
 from repro.models import model as M
 from repro.serving.engine import ServingEngine
 
@@ -60,11 +61,61 @@ def test_more_requests_than_slots(rng):
 
 
 def test_oversized_request_rejected():
+    """A request that cannot fit raises at submit instead of finishing
+    with no tokens; the engine stays idle and serves the next one."""
     cfg = get_smoke_config("olmo-1b").replace(remat=False)
     eng = ServingEngine(cfg, n_slots=1, max_context=32, chunk=8)
-    r = eng.submit(list(range(30)), max_new_tokens=10)
-    eng.run_until_idle()
-    assert r.finished and r.generated == []
+    with pytest.raises(ContextOverflowError):
+        eng.submit(list(range(30)), max_new_tokens=10)
+    with pytest.raises(ContextOverflowError):
+        eng.generate(list(range(30)), max_new_tokens=10)
+    assert not eng.waiting and not any(eng.active)
+    assert len(eng.generate(list(range(22)), max_new_tokens=10)) == 10
+
+
+def _local_provider(max_context):
+    from repro.core.provider import LocalJaxProvider
+    return LocalJaxProvider("olmo-1b", max_context=max_context)
+
+
+def test_provider_overflow_counts_its_own_tokens():
+    """The provider checks its byte-level token count, not the planner's
+    0.33-per-character estimate: a prompt the estimate lets through but
+    whose bytes overflow the engine raises ContextOverflowError, and the
+    engine is never reached."""
+    from repro.core.metaprompt import build_metaprompt
+    from repro.core.provider import estimate_tokens
+    from repro.core.resources import ModelResource
+
+    prov = _local_provider(max_context=512)
+    model = ModelResource(name="local", version=1, arch="olmo-1b",
+                          context_window=4096, max_output_tokens=2)
+    mp = build_metaprompt("complete", "echo", [{"t": "x" * 200}], "xml")
+    assert estimate_tokens(mp.text) + 2 < 512 < len(mp.text.encode())
+    with pytest.raises(ContextOverflowError):
+        prov.complete(model, mp, 1)
+    assert prov.stats.calls == 0 and prov.engine.steps == 0
+    ok = build_metaprompt("complete", "echo", [{"t": "x"}], "xml")
+    assert len(prov.complete(model, ok, 1)) == 1
+    assert prov.engine.steps > 0
+
+
+def test_provider_overflow_backs_off_to_null():
+    """Through llm_complete the overflow drives the adaptive batcher: the
+    batch splits down to single tuples, the row that still cannot fit
+    is NULL, and every other row is answered."""
+    from repro.core import SemanticContext, llm_complete
+
+    prov = _local_provider(max_context=512)
+    ctx = SemanticContext(provider=prov)
+    rows = [{"t": f"r{i}"} for i in range(4)] + [{"t": "y" * 300}]
+    out = llm_complete(ctx, {"model": "local", "context_window": 4096,
+                             "max_output_tokens": 2},
+                       {"prompt": "echo"}, rows)
+    assert out[-1] is None
+    assert all(o is not None for o in out[:-1])
+    rep = ctx.last_report()
+    assert rep.retries > 0 and rep.nulls == 1
 
 
 def test_embedding_deterministic_and_normalised():
@@ -97,3 +148,27 @@ def test_chunked_prefill_equals_full_prefill(rng):
         np.testing.assert_allclose(np.asarray(lg[:, -1]),
                                    np.asarray(lg_full[:, -1]),
                                    atol=2e-3, rtol=2e-3)
+
+
+def test_score_matches_float32_forward(rng):
+    """engine.score runs the request split (whole chunks through chunked
+    prefill, the tail through decode) and its teacher-forced logits
+    track a float32 forward of the same parameters."""
+    cfg = get_smoke_config("olmo-1b").replace(remat=False)
+    eng = ServingEngine(cfg, n_slots=2, max_context=64, chunk=8, seed=0)
+    toks = [int(t) for t in rng.integers(0, cfg.vocab_size, 29)]
+    got = eng.score(toks)
+    assert got.shape == (29, cfg.vocab_size)
+    assert eng.steps == 0 and not any(eng.active)
+
+    cfg32 = cfg.replace(param_dtype="float32", compute_dtype="float32")
+    p32 = jax.tree.map(lambda a: a.astype(jnp.float32), eng.params)
+    with jax.default_matmul_precision("highest"):
+        ref, _ = M.forward_train(cfg32, p32,
+                                 {"tokens": jnp.asarray([toks], jnp.int32)})
+    ref = np.asarray(ref[0, :, :cfg.vocab_size])
+    # bf16 serving vs f32 reference: a few bf16 ulps of |logits| <= ~4
+    np.testing.assert_allclose(got, ref, atol=0.06, rtol=0)
+    assert (got.argmax(-1) == ref.argmax(-1)).mean() > 0.9
+    with pytest.raises(ContextOverflowError):
+        eng.score(list(range(65)))
