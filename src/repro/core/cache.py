@@ -17,6 +17,8 @@ from collections import OrderedDict
 from pathlib import Path
 from typing import Any, Optional
 
+from . import telemetry
+
 logger = logging.getLogger(__name__)
 
 
@@ -254,13 +256,14 @@ def corpus_fingerprint(texts) -> str:
     order is part of the identity.  Each text is length-prefixed so no
     choice of text content can make two different corpora collide
     (separator bytes inside a text cannot fake a document boundary)."""
-    h = hashlib.sha256()
-    for t in texts:
-        payload = str(t).encode()
-        h.update(str(len(payload)).encode())
-        h.update(b":")
-        h.update(payload)
-    return h.hexdigest()
+    with telemetry.span("retrieval.fingerprint"):
+        h = hashlib.sha256()
+        for t in texts:
+            payload = str(t).encode()
+            h.update(str(len(payload)).encode())
+            h.update(b":")
+            h.update(payload)
+        return h.hexdigest()
 
 
 # persisted vector indexes are whole embedding matrices; keep only the

@@ -23,11 +23,13 @@ import json
 import re
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
+from . import telemetry
 from .batching import ContextOverflowError
 from .metaprompt import MetaPrompt
 from .resources import ModelResource
@@ -224,6 +226,18 @@ class LocalJaxProvider(BaseProvider):
         # for this provider comes from the engine's own continuous
         # batching, not from overlapped calls.
         self._engine_lock = threading.Lock()
+        telemetry.install()
+
+    @contextmanager
+    def _engine(self):
+        """The engine, held by this thread alone; the time spent waiting
+        for it is a ``provider.engine_wait`` span."""
+        with telemetry.span("provider.engine_wait"):
+            self._engine_lock.acquire()
+        try:
+            yield self.engine
+        finally:
+            self._engine_lock.release()
 
     @staticmethod
     def _tokenize(text: str, vocab: int) -> list[int]:
@@ -245,8 +259,8 @@ class LocalJaxProvider(BaseProvider):
             raise ContextOverflowError(
                 f"{len(prompt)} prompt + {max_new} output tokens > "
                 f"context window {window}")
-        with self._engine_lock:
-            toks = self.engine.generate(prompt, max_new_tokens=max_new)
+        with self._engine() as engine:
+            toks = engine.generate(prompt, max_new_tokens=max_new)
         text = self._detokenize(toks)
         self.stats.add(calls=1, prompt_tokens=len(prompt),
                        output_tokens=len(toks),
@@ -260,8 +274,8 @@ class LocalJaxProvider(BaseProvider):
 
     def embed(self, model, texts):
         vocab = self.engine.cfg.vocab_size
-        with self._engine_lock:
-            out = self.engine.embed_batch(
+        with self._engine() as engine:
+            out = engine.embed_batch(
                 [self._tokenize(t, vocab) for t in texts])
         self.stats.add(calls=1)
         return out

@@ -73,6 +73,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
+from . import telemetry
 from .batching import BatchStats, ContextOverflowError, plan_batches
 from .resources import ModelResource
 
@@ -870,7 +871,8 @@ class RequestScheduler:
                 self.stats.max_inflight = self._executing
         t0 = time.monotonic()
         try:
-            out = job.run(batch)
+            with telemetry.span("scheduler.dispatch"):
+                out = job.run(batch)
         except ContextOverflowError:
             with job._lock:
                 job.stats.retries += 1

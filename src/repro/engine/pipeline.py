@@ -99,6 +99,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.core import functions as F
+from repro.core import telemetry
 from repro.core.functions import SemanticContext
 from repro.core.metaprompt import build_multi_task
 
@@ -170,10 +171,11 @@ class Pipeline:
                                                         "name": name})]
 
     def _add(self, op: str, fn, **info) -> "Pipeline":
-        p = Pipeline.__new__(Pipeline)
-        p.ctx, p.source = self.ctx, self.source
-        p.nodes = self.nodes + [PlanNode(op, info, fn)]
-        return p
+        with telemetry.span("pipeline.build"):
+            p = Pipeline.__new__(Pipeline)
+            p.ctx, p.source = self.ctx, self.source
+            p.nodes = self.nodes + [PlanNode(op, info, fn)]
+            return p
 
     # ---- relational --------------------------------------------------------
     def select(self, *names):
@@ -238,11 +240,13 @@ class Pipeline:
     def _add_retrieval(self, op: str, info: dict) -> "Pipeline":
         from .retrieval_ops import make_retrieval_fn, retrieval_outputs
         from repro.core.cache import corpus_fingerprint
-        info["corpus_rows"] = len(info["corpus"])
-        info["corpus_fp"] = corpus_fingerprint(
-            [str(x) for x in info["corpus"].column(info["doc_col"])])
-        info["outs"] = retrieval_outputs(info)
-        return self._add(op, make_retrieval_fn(self.ctx, op, info), **info)
+        with telemetry.span("pipeline.build"):
+            info["corpus_rows"] = len(info["corpus"])
+            info["corpus_fp"] = corpus_fingerprint(
+                [str(x) for x in info["corpus"].column(info["doc_col"])])
+            info["outs"] = retrieval_outputs(info)
+            return self._add(op, make_retrieval_fn(self.ctx, op, info),
+                             **info)
 
     @staticmethod
     def _ann_info(ann, recall_target, nprobe, nlist) -> dict:
@@ -593,21 +597,23 @@ class Pipeline:
         if objective is not None:
             self.ctx.objective = objective
         try:
-            if optimize:
-                opt = self._plan(speculate)
-                if verify != "off":
-                    # discharge the optimizer's soundness obligations
-                    # on the rewritten plan before it executes
-                    self._verify_rewrites(verify, opt)
-                nodes = opt.nodes
-            else:
-                nodes = self.nodes
-            self._executed_nodes = nodes
-            self._executed_optimized = optimize
-            t = self.source
-            base = len(self.ctx.reports)
-            groups = (self._dispatch_groups(nodes) if parallel
-                      else [[n] for n in nodes])
+            with telemetry.span("pipeline.optimize"):
+                if optimize:
+                    opt = self._plan(speculate)
+                    if verify != "off":
+                        # discharge the optimizer's soundness
+                        # obligations on the rewritten plan before it
+                        # executes
+                        self._verify_rewrites(verify, opt)
+                    nodes = opt.nodes
+                else:
+                    nodes = self.nodes
+                self._executed_nodes = nodes
+                self._executed_optimized = optimize
+                t = self.source
+                base = len(self.ctx.reports)
+                groups = (self._dispatch_groups(nodes) if parallel
+                          else [[n] for n in nodes])
             try:
                 for group in groups:
                     if len(group) > 1:

@@ -47,6 +47,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.cache import corpus_fingerprint
 from repro.core.functions import (SemanticContext, embedding_pack_key,
                                   llm_embedding, llm_rerank)
@@ -114,14 +115,19 @@ def _embed_corpus_and_queries(ctx: SemanticContext, model_spec,
     embed dispatches run on concurrent threads under an activated
     embedding pack identity, so the corpus tail batch and the (small)
     query batch merge into one provider request."""
-    model = ctx.resolve_model(model_spec)
-    if fingerprint is None:
-        fingerprint = corpus_fingerprint(corpus_texts)
-    cached = ctx.index_cached(model.ref, fingerprint)
+    def lookup():
+        with telemetry.span("retrieval.index"):
+            return ensure_index(ctx, model_spec, corpus_texts,
+                                fingerprint=fingerprint)
+
+    with telemetry.span("retrieval.index"):
+        model = ctx.resolve_model(model_spec)
+        if fingerprint is None:
+            fingerprint = corpus_fingerprint(corpus_texts)
+        cached = ctx.index_cached(model.ref, fingerprint)
     if (cached or not queries or not ctx.copack
             or ctx.scheduler is None or not ctx.enable_batching):
-        index, _ = ensure_index(ctx, model_spec, corpus_texts,
-                                fingerprint=fingerprint)
+        index, _ = lookup()
         qv = llm_embedding(ctx, model_spec, queries)
         return index, qv
 
@@ -144,12 +150,8 @@ def _embed_corpus_and_queries(ctx: SemanticContext, model_spec,
         threads = [
             # exactly two bounded submitters under one activated pack
             # identity, joined below  # flocklint: ignore[FLKL106]
-            threading.Thread(
-                target=worker,
-                args=(0, lambda: ensure_index(ctx, model_spec,
-                                              corpus_texts,
-                                              fingerprint=fingerprint)),
-                name="flockjax-embed-corpus"),
+            threading.Thread(target=worker, args=(0, lookup),
+                             name="flockjax-embed-corpus"),
             # flocklint: ignore[FLKL106]
             threading.Thread(
                 target=worker,
@@ -175,12 +177,14 @@ def _vector_candidates(ctx: SemanticContext, info: dict,
     scan only matching docs), unpruned predicate (scan all, mask the
     ranking) — produce identical candidates; only the embed volume
     differs."""
-    corpus_texts = [str(x) for x in
-                    info["corpus"].column(info["doc_col"])]
-    n = len(corpus_texts)
-    full = len(sel) == n
-    pruned = bool(info.get("prune_corpus")) and not full
-    texts = ([corpus_texts[i] for i in sel] if pruned else corpus_texts)
+    with telemetry.span("retrieval.index"):
+        corpus_texts = [str(x) for x in
+                        info["corpus"].column(info["doc_col"])]
+        n = len(corpus_texts)
+        full = len(sel) == n
+        pruned = bool(info.get("prune_corpus")) and not full
+        texts = ([corpus_texts[i] for i in sel] if pruned
+                 else corpus_texts)
     if not texts:
         return [([], []) for _ in queries]
     fp = None if pruned else info.get("corpus_fp")
@@ -203,18 +207,21 @@ def _vector_candidates(ctx: SemanticContext, info: dict,
                 recall_target=info.get("recall_target"))
         else:
             s, li = index.topk(qv, min(depth, len(texts)))
-        for r in range(len(queries)):
-            ids = ([sel[int(j)] for j in li[r]] if pruned
-                   else [int(j) for j in li[r]])
-            out.append((ids, [float(x) for x in s[r]]))
+        with telemetry.span("retrieval.join"):
+            for r in range(len(queries)):
+                ids = ([sel[int(j)] for j in li[r]] if pruned
+                       else [int(j) for j in li[r]])
+                out.append((ids, [float(x) for x in s[r]]))
     else:
         s, li = index.topk(qv, n)          # full ranking, then mask
-        selset = set(sel)
-        for r in range(len(queries)):
-            pairs = [(int(i), float(sc))
-                     for i, sc in zip(li[r], s[r]) if int(i) in selset]
-            pairs = pairs[:depth]
-            out.append(([p[0] for p in pairs], [p[1] for p in pairs]))
+        with telemetry.span("retrieval.join"):
+            selset = set(sel)
+            for r in range(len(queries)):
+                pairs = [(int(i), float(sc))
+                         for i, sc in zip(li[r], s[r]) if int(i) in selset]
+                pairs = pairs[:depth]
+                out.append(([p[0] for p in pairs],
+                            [p[1] for p in pairs]))
     return out
 
 
@@ -235,7 +242,8 @@ def _bm25_candidates(info: dict, queries: List[str], sel: List[int],
 
 def _candidates(ctx: SemanticContext, op: str, info: dict,
                 queries: List[str]) -> List[Tuple[List[int], List[float]]]:
-    sel = _corpus_selection(info)
+    with telemetry.span("retrieval.index"):
+        sel = _corpus_selection(info)
     k_eff = min(info["k"], len(sel))
     if op == "bm25_topk":
         return _bm25_candidates(info, queries, sel, k_eff)
@@ -295,7 +303,8 @@ def make_retrieval_fn(ctx: SemanticContext, op: str, info: dict):
             cols[rank_col] = list(range(1, len(ids) + 1))
             return Table(cols)
 
-        return t.lateral(child)
+        with telemetry.span("retrieval.join"):
+            return t.lateral(child)
 
     return fn
 

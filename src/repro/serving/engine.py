@@ -19,7 +19,6 @@ sampling are exercised end-to-end.
 from __future__ import annotations
 
 import itertools
-import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence
 
@@ -27,9 +26,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.batching import ContextOverflowError
 from repro.models import model as M
 from repro.models.config import ModelConfig
+from repro.serving.steps import make_embed_step
 
 
 @dataclass
@@ -39,8 +40,6 @@ class Request:
     max_new_tokens: int = 32
     eos_token: int = -1              # -1: never stop early
     generated: List[int] = field(default_factory=list)
-    # wall-clock arrival timestamp  # flocklint: ignore[FLKL101]
-    submitted_at: float = field(default_factory=time.time)
     finished: bool = False
     slot: int = -1
     pos: int = 0                     # tokens of this request already cached
@@ -69,12 +68,23 @@ class ServingEngine:
         self.cur_tok = np.zeros(n_slots, np.int32)
         self.steps = 0
 
+        # named functions, so that compiles and the device trace's
+        # modules say which step is which
         cfgc = self.cfg
-        self._decode = jax.jit(
-            lambda p, t, c, pos: M.decode_step(cfgc, p, t, c, pos))
-        self._extend = jax.jit(
-            lambda p, t, c, off: M.prefill_chunk(cfgc, p, t, c, off))
-        self._embed_cache = {}
+        embed_step = make_embed_step(cfgc)
+
+        def engine_decode(p, t, c, pos):
+            return M.decode_step(cfgc, p, t, c, pos)
+
+        def engine_prefill(p, t, c, off):
+            return M.prefill_chunk(cfgc, p, t, c, off)
+
+        def engine_embed(p, batch):
+            return embed_step(p, batch)
+
+        self._decode = jax.jit(engine_decode)
+        self._extend = jax.jit(engine_prefill)
+        self._embed = jax.jit(engine_embed)
 
     # ------------------------------------------------------------------ API
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 32,
@@ -157,50 +167,59 @@ class ServingEngine:
             # other slots point at their current pos so their cache rows
             # are overwritten with identical values (harmless: we reuse the
             # current token, and the masked write targets the same cells).
-            start = len(req.prompt) - req.pending_prompt
-            chunk_toks = req.prompt[start:start + self.chunk]
-            toks = np.zeros((self.n_slots, self.chunk), np.int32)
-            toks[slot] = chunk_toks
-            offs = np.array(self.pos, np.int32)
-            offs_vec = offs.copy()
-            # rows without work: point their writes at their own positions
-            # (re-writing the same K/V values they already hold)
-            logits, new_cache = self._extend(
-                self.params, jnp.asarray(toks), self.cache,
-                jnp.asarray(offs_vec))
-            # merge: keep new cache rows only for the working slot
-            self.cache = _merge_row(self.cache, new_cache, slot)
-            req.pos += self.chunk
-            self.pos[slot] += self.chunk
-            req.pending_prompt -= self.chunk
+            with telemetry.span("engine.prefill"):
+                start = len(req.prompt) - req.pending_prompt
+                chunk_toks = req.prompt[start:start + self.chunk]
+                toks = np.zeros((self.n_slots, self.chunk), np.int32)
+                toks[slot] = chunk_toks
+                # rows without work: point their writes at their own
+                # positions (re-writing the same K/V values they hold)
+                offs = np.array(self.pos, np.int32)
+                logits, new_cache = self._extend(
+                    self.params, jnp.asarray(toks), self.cache,
+                    jnp.asarray(offs))
+                # merge: keep new cache rows only for the working slot
+                self.cache = engine_merge_row(self.cache, new_cache,
+                                              np.int32(slot))
+                req.pos += self.chunk
+                self.pos[slot] += self.chunk
+                req.pending_prompt -= self.chunk
             return True      # one chunk per engine step keeps latency fair
         return False
 
     def step(self):
-        self._admit()
+        with telemetry.span("engine.admit"):
+            self._admit()
         self.steps += 1
         if self._prefill_work():
             return
         # build the decode batch: remaining prompt tokens are fed one at a
         # time (teacher forcing); slots past their prompt sample greedily
-        any_active = False
+        n_active = 0
         toks = np.zeros((self.n_slots, 1), np.int32)
         for slot, req in enumerate(self.active):
             if req is None:
                 continue
-            any_active = True
+            n_active += 1
             if req.pending_prompt > 0:
                 idx = len(req.prompt) - req.pending_prompt
                 toks[slot, 0] = req.prompt[idx]
             else:
                 toks[slot, 0] = self.cur_tok[slot]
-        if not any_active:
+        if not n_active:
             return
-        pos_vec = jnp.asarray(self.pos, jnp.int32)
-        logits, self.cache = self._decode(
-            self.params, jnp.asarray(toks), self.cache, pos_vec)
-        nxt = np.asarray(jnp.argmax(
-            _mask_vocab(self.cfg, logits[:, 0]), axis=-1), np.int32)
+        with telemetry.span("engine.decode"):
+            pos_vec = jnp.asarray(self.pos, jnp.int32)
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(toks), self.cache, pos_vec)
+        telemetry.count("engine.slot_steps", n_active)
+        with telemetry.span("engine.sample"):
+            nxt = np.asarray(jnp.argmax(
+                _mask_vocab(self.cfg, logits[:, 0]), axis=-1), np.int32)
+            self._advance(nxt)
+
+    def _advance(self, nxt: np.ndarray):
+        """Each active slot past one decode step: its sampled token."""
         for slot, req in enumerate(self.active):
             if req is None:
                 continue
@@ -235,16 +254,14 @@ class ServingEngine:
 
     def embed_batch(self, token_lists) -> np.ndarray:
         """One padded forward for N texts — the 48x-style batching lever."""
-        from repro.serving.steps import make_embed_step
-        longest = max((len(t) for t in token_lists), default=1)
-        L = 1 << max(5, (max(longest, 1) - 1).bit_length())
-        if L not in self._embed_cache:
-            self._embed_cache[L] = jax.jit(make_embed_step(self.cfg))
-        toks = np.full((len(token_lists), L), -1, np.int32)
-        for i, t in enumerate(token_lists):
-            toks[i, :len(t)] = t
-        emb = self._embed_cache[L](self.params, {"tokens": jnp.asarray(toks)})
-        return np.asarray(emb)
+        with telemetry.span("engine.embed"):
+            longest = max((len(t) for t in token_lists), default=1)
+            L = 1 << max(5, (max(longest, 1) - 1).bit_length())
+            toks = np.full((len(token_lists), L), -1, np.int32)
+            for i, t in enumerate(token_lists):
+                toks[i, :len(t)] = t
+            emb = self._embed(self.params, {"tokens": jnp.asarray(toks)})
+            return np.asarray(emb)
 
 
 def _mask_vocab(cfg, logits):
@@ -254,7 +271,8 @@ def _mask_vocab(cfg, logits):
     return logits
 
 
-def _merge_row(old_tree, new_tree, row: int):
+@jax.jit
+def engine_merge_row(old_tree, new_tree, row):
     """Take row ``row`` (batch dim = axis 1 under the stacked-layer axis 0)
     from new_tree, everything else from old_tree."""
     def merge(o, n):
