@@ -45,6 +45,11 @@ SPANS = (
     "python.gc",              # a garbage collection (after install())
 )
 WAITS = ("provider.engine_wait",)
+# Counters are free-form (``count(name, n)``); the program's are:
+#   engine.slot_steps            active slots, added once per decode step
+#   retrieval.fingerprint_reuse  a Table.text_fingerprint memo hit, where
+#                                a retrieval.fingerprint span would be
+#   compile.<function>           a backend compilation (after install())
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 

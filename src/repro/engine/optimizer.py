@@ -88,6 +88,7 @@ rewritten plan.
 
 from __future__ import annotations
 
+import itertools
 import math
 import threading
 from dataclasses import dataclass, field
@@ -370,7 +371,7 @@ def _filter_estimate(ctx: SemanticContext, member: dict, n: int,
 def _avg_text_tokens(values) -> int:
     """Mean token estimate of raw text values (corpus docs, query
     strings), sampled like ``_avg_tuple_tokens``."""
-    vals = list(values)[:_SAMPLE_ROWS]
+    vals = list(itertools.islice(values, _SAMPLE_ROWS))
     if not vals:
         return 1
     return max(1, sum(estimate_tokens(str(v)) for v in vals) // len(vals))

@@ -239,11 +239,10 @@ class Pipeline:
     # ---- retrieval operators -------------------------------------------------
     def _add_retrieval(self, op: str, info: dict) -> "Pipeline":
         from .retrieval_ops import make_retrieval_fn, retrieval_outputs
-        from repro.core.cache import corpus_fingerprint
         with telemetry.span("pipeline.build"):
             info["corpus_rows"] = len(info["corpus"])
-            info["corpus_fp"] = corpus_fingerprint(
-                [str(x) for x in info["corpus"].column(info["doc_col"])])
+            _, info["corpus_fp"] = info["corpus"].text_fingerprint(
+                info["doc_col"])
             info["outs"] = retrieval_outputs(info)
             return self._add(op, make_retrieval_fn(self.ctx, op, info),
                              **info)

@@ -85,14 +85,15 @@ def pushed_candidate_k(k: int) -> int:
 # ---------------------------------------------------------------------------
 # candidate generation
 # ---------------------------------------------------------------------------
-def _corpus_selection(info: dict) -> List[int]:
-    """Doc ids satisfying the node's corpus predicate (all ids without
-    one) — identical whether or not the optimizer pruned, so the rewrite
-    can only change WHERE the predicate is applied, never the result."""
+def _corpus_selection(info: dict) -> Sequence[int]:
+    """Doc ids satisfying the node's corpus predicate (all ids, as a
+    ``range``, without one) — identical whether or not the optimizer
+    pruned, so the rewrite can only change WHERE the predicate is
+    applied, never the result."""
     corpus = info["corpus"]
     pred = info.get("corpus_filter")
     if pred is None:
-        return list(range(len(corpus)))
+        return range(len(corpus))
     return [i for i, r in enumerate(corpus.rows()) if pred(r)]
 
 
@@ -108,7 +109,7 @@ def _ranked(scores: np.ndarray, eligible: Sequence[int],
 
 
 def _embed_corpus_and_queries(ctx: SemanticContext, model_spec,
-                              corpus_texts: List[str],
+                              corpus_texts: Sequence[str],
                               queries: List[str], fingerprint):
     """Corpus index (via ``ensure_index``) + query vectors.  When the
     corpus is not memoised and the context allows co-packing, the two
@@ -170,7 +171,7 @@ def _embed_corpus_and_queries(ctx: SemanticContext, model_spec,
 
 
 def _vector_candidates(ctx: SemanticContext, info: dict,
-                       queries: List[str], sel: List[int],
+                       queries: List[str], sel: Sequence[int],
                        depth: int) -> List[Tuple[List[int], List[float]]]:
     """Per-query vector candidates at ``depth``: (doc ids, cosine
     scores).  Three modes — no predicate (scan all), pruned (embed and
@@ -178,8 +179,8 @@ def _vector_candidates(ctx: SemanticContext, info: dict,
     ranking) — produce identical candidates; only the embed volume
     differs."""
     with telemetry.span("retrieval.index"):
-        corpus_texts = [str(x) for x in
-                        info["corpus"].column(info["doc_col"])]
+        corpus_texts, corpus_fp = info["corpus"].text_fingerprint(
+            info["doc_col"])
         n = len(corpus_texts)
         full = len(sel) == n
         pruned = bool(info.get("prune_corpus")) and not full
@@ -187,7 +188,7 @@ def _vector_candidates(ctx: SemanticContext, info: dict,
                  else corpus_texts)
     if not texts:
         return [([], []) for _ in queries]
-    fp = None if pruned else info.get("corpus_fp")
+    fp = None if pruned else corpus_fp
     index, qv = _embed_corpus_and_queries(ctx, info["model"], texts,
                                           queries, fp)
     # ANN routing: the optimizer's ann_select resolution wins; a forced
@@ -225,7 +226,7 @@ def _vector_candidates(ctx: SemanticContext, info: dict,
     return out
 
 
-def _bm25_candidates(info: dict, queries: List[str], sel: List[int],
+def _bm25_candidates(info: dict, queries: List[str], sel: Sequence[int],
                      depth: int) -> List[Tuple[List[int], List[float]]]:
     """Per-query BM25 candidates at ``depth``.  The index is ALWAYS
     built over the full corpus (idf/avgdl are corpus statistics; a
@@ -233,7 +234,7 @@ def _bm25_candidates(info: dict, queries: List[str], sel: List[int],
     bm = info.get("_bm25")
     if bm is None:
         bm = info["_bm25"] = BM25Index.build(
-            [str(x) for x in info["corpus"].column(info["doc_col"])])
+            info["corpus"].text_fingerprint(info["doc_col"])[0])
     # all pending queries score in ONE vectorized pass over the
     # postings (bit-identical rows to per-query score(), see bm25.py)
     scores = bm.score_many([str(q) for q in queries])

@@ -3,13 +3,22 @@
 Columns are python lists / numpy arrays of equal length.  Operations are
 vectorised where possible and always return new Tables (immutability keeps
 plan re-execution deterministic for the cache/dedup benchmarks).
+
+Contract: a column is never edited in place.  Change a table by deriving
+a new one (``with_column``, ``filter``, ``select``, ...); every operation
+builds fresh column lists.  ``text_fingerprint`` relies on this: it
+memoises a column's strings and content fingerprint on the table, and
+only notices a column that was reassigned or changed length.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Sequence
+from typing import Callable, Dict, List, Sequence, Tuple
 
 import numpy as np
+
+from repro.core import telemetry
+from repro.core.cache import corpus_fingerprint
 
 
 class Table:
@@ -18,6 +27,8 @@ class Table:
         if len(lens) > 1:
             raise ValueError(f"ragged columns: { {k: len(v) for k, v in columns.items()} }")
         self.columns = {k: list(v) for k, v in columns.items()}
+        # column name -> (column list, its length, texts, fingerprint)
+        self._text_fp: Dict[str, tuple] = {}
 
     # ---- basics ------------------------------------------------------------
     def __len__(self):
@@ -29,6 +40,22 @@ class Table:
 
     def column(self, name: str) -> list:
         return self.columns[name]
+
+    def text_fingerprint(self, name: str) -> Tuple[Tuple[str, ...], str]:
+        """``(texts, fp)``: column ``name`` as strings and their
+        ``corpus_fingerprint``, computed on first use and memoised on
+        this table.  The memo holds the column list itself and is reused
+        only while ``self.columns[name]`` is that list at that length,
+        so a reassigned or appended column is fingerprinted afresh."""
+        col = self.columns[name]
+        memo = self._text_fp.get(name)
+        if memo is not None and memo[0] is col and memo[1] == len(col):
+            telemetry.count("retrieval.fingerprint_reuse")
+            return memo[2], memo[3]
+        texts = tuple([str(x) for x in col])
+        fp = corpus_fingerprint(texts)
+        self._text_fp[name] = (col, len(col), texts, fp)
+        return texts, fp
 
     def rows(self) -> List[dict]:
         names = self.column_names

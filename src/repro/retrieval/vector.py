@@ -289,7 +289,8 @@ def ensure_index(ctx, model_spec, texts: Sequence[str],
     from repro.core.cache import corpus_fingerprint
     from repro.core.functions import llm_embedding
 
-    texts = list(texts)
+    if not isinstance(texts, (list, tuple)):
+        texts = list(texts)
     model = ctx.resolve_model(model_spec)
     if fingerprint is None:
         fingerprint = corpus_fingerprint(texts)

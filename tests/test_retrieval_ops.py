@@ -26,7 +26,7 @@ import pytest
 
 from repro.core import (MockProvider, PredictionCache, RequestScheduler,
                         SemanticContext, corpus_fingerprint, llm_embedding,
-                        rrf)
+                        rrf, telemetry)
 from repro.core.cache import IndexStore
 from repro.core.fusion import (combanz, combmed, combmnz, combsum,
                                fusion)
@@ -663,3 +663,90 @@ def test_select_pushdown_keeps_grouped_rerank_key():
     ctx4 = SemanticContext(provider=MockProvider())
     assert rows3 == build(ctx4, ("q", "content")) \
         .collect(optimize=False).rows()
+
+
+# ---------------------------------------------------------------------------
+# corpus fingerprint memo: one computation per Table column
+# ---------------------------------------------------------------------------
+def _fp_counts(before, after):
+    """(fingerprint spans, memo reuses) between two telemetry snapshots."""
+    def n(name):
+        return (after.get(name, {"n": 0})["n"]
+                - before.get(name, {"n": 0})["n"])
+    return n("retrieval.fingerprint"), n("retrieval.fingerprint_reuse")
+
+
+def test_two_plans_over_one_table_fingerprint_once():
+    corpus = make_corpus()
+    ctx = SemanticContext(provider=MockProvider())
+    before = telemetry.snapshot()
+    for _ in range(2):
+        (Pipeline(ctx, queries_table(), "queries")
+         .vector_topk("score", EMB, "q", corpus, k=5, doc_col="content")
+         .collect())
+    # plan 1's build computes; its executor and plan 2's build and
+    # executor read the memo
+    assert _fp_counts(before, telemetry.snapshot()) == (1, 3)
+    texts, fp = corpus.text_fingerprint("content")
+    assert isinstance(texts, tuple)
+    assert list(texts) == [str(x) for x in corpus.column("content")]
+    assert fp == corpus_fingerprint(texts)
+
+
+@pytest.mark.parametrize("change", ["reassign", "append"])
+def test_reassigned_or_appended_column_is_fingerprinted_afresh(change):
+    corpus = make_corpus(12)
+    _, fp0 = corpus.text_fingerprint("content")
+    if change == "reassign":
+        corpus.columns["content"] = [f"other {i}" for i in range(12)]
+    else:
+        corpus.columns["content"].append("one more doc")
+    before = telemetry.snapshot()
+    texts, fp = corpus.text_fingerprint("content")
+    assert _fp_counts(before, telemetry.snapshot()) == (1, 0)
+    assert list(texts) == corpus.column("content")
+    assert fp == corpus_fingerprint(corpus.column("content")) != fp0
+    before = telemetry.snapshot()
+    assert corpus.text_fingerprint("content") == (texts, fp)
+    assert _fp_counts(before, telemetry.snapshot()) == (0, 1)
+
+
+@pytest.mark.parametrize("derive", ["with_column_same", "with_column_new",
+                                    "filter"])
+def test_derived_table_is_fingerprinted_afresh(derive):
+    corpus = make_corpus(12)
+    _, fp0 = corpus.text_fingerprint("content")
+    if derive == "with_column_same":
+        derived = corpus.with_column("tag", ["t"] * 12)
+    elif derive == "with_column_new":
+        derived = corpus.with_column("content",
+                                     [f"new {i}" for i in range(12)])
+    else:
+        derived = corpus.filter(lambda r: r["year"] >= 2003)
+    before = telemetry.snapshot()
+    _, fp = derived.text_fingerprint("content")
+    assert _fp_counts(before, telemetry.snapshot()) == (1, 0)
+    assert fp == corpus_fingerprint(derived.column("content"))
+    assert (fp == fp0) == (derive == "with_column_same")
+
+
+@pytest.mark.parametrize("op", ["vector_topk", "bm25_topk", "hybrid_topk"])
+def test_memoised_plan_rows_match_fresh_table(op):
+    def plan(ctx, corpus):
+        pipe = Pipeline(ctx, queries_table(), "queries")
+        if op == "bm25_topk":
+            pipe = pipe.bm25_topk("score", "q", corpus, k=5,
+                                  doc_col="content")
+        else:
+            pipe = getattr(pipe, op)("score", EMB, "q", corpus, k=5,
+                                     doc_col="content")
+        return pipe.collect().rows()
+
+    corpus = make_corpus()
+    ctx = SemanticContext(provider=MockProvider())
+    plan(ctx, corpus)
+    before = telemetry.snapshot()
+    memoised = plan(ctx, corpus)
+    assert _fp_counts(before, telemetry.snapshot())[0] == 0
+    fresh = Table({c: list(v) for c, v in corpus.columns.items()})
+    assert memoised == plan(SemanticContext(provider=MockProvider()), fresh)
