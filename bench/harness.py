@@ -19,6 +19,14 @@ as ``setup_s`` and is not in the window.  With ``trace`` the window runs
 under the JAX profiler, for at most ``TRACE_WINDOW_S``, and the
 per-layer metrics are read from the trace and the counters of that
 window; without it, the end-to-end metrics.
+
+A reader gets ``rec``: the window's plans, latencies and counters, the
+configuration, the chip's peaks, ``telemetry`` (the program's spans and
+counters over the window, ``{name: {"n", "s"}}`` as
+``repro.core.telemetry.snapshot()`` names them; ``{}`` where the program
+has none), ``n_slots`` (the serving engine's decode slots) and, traced,
+``trace_events`` (``bench/trace.py``'s ``flatten``) and ``trace`` (its
+``reduce``).
 """
 
 from __future__ import annotations
@@ -110,26 +118,22 @@ def _memory_peak(devices) -> int:
     return int(max(peaks)) if peaks else 0
 
 
-class CompileCount:
-    """Backend compilations while active (JAX's monitoring events)."""
+def _telemetry() -> dict:
+    """The program's span and counter totals since the process started
+    (``repro.core.telemetry``), or ``{}`` where the program has none."""
+    try:
+        from repro.core import telemetry
+    except ImportError:
+        return {}
+    return telemetry.snapshot()
 
-    EVENT = "/jax/core/compile/backend_compile_duration"
 
-    def __init__(self):
-        self.n = 0
-
-    def __call__(self, event, duration, **kw):
-        if event == self.EVENT:
-            self.n += 1
-
-    def __enter__(self):
-        import jax
-        jax.monitoring.register_event_duration_secs_listener(self)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-        jax.monitoring.unregister_event_duration_listener(self)
+def _since(before: dict, after: dict) -> dict:
+    """Per-name difference of two telemetry snapshots."""
+    zero = {"n": 0, "s": 0.0}
+    return {k: {"n": v["n"] - before.get(k, zero)["n"],
+                "s": v["s"] - before.get(k, zero)["s"]}
+            for k, v in after.items()}
 
 
 def run(cell: dict, seed: int, seconds: float, trace: bool,
@@ -185,7 +189,8 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
         seconds = min(seconds, TRACE_WINDOW_S)
         jax.profiler.start_trace(trace_dir)
     plans, latencies, failed = [], [], 0
-    with CompileCount() as compiles, TraceAnnotation("window"):
+    telemetry_before = _telemetry()
+    with TraceAnnotation("window"):
         t0 = time.perf_counter()
         t_end = t0
         while t_end - t0 < seconds:
@@ -204,6 +209,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
             latencies.append(t_end - t_plan)
             if out is not None:
                 plans.append((rows, out))
+    telemetry = _since(telemetry_before, _telemetry())
     if trace:
         t_stop = time.perf_counter()
         jax.profiler.stop_trace()
@@ -235,6 +241,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
                        int(config["model"]["hidden_size"]),
                        int(traffic["rows_per_plan"]))
         if "corpus" in traffic else None,
+        "telemetry": telemetry, "n_slots": prov.engine.n_slots,
         "trace": None,
     }
 
@@ -243,7 +250,9 @@ def run(cell: dict, seed: int, seconds: float, trace: bool,
     sched.shutdown()
     del prov, ctx, sched, corpus
     gc.collect()
-    print(f"compiles in the window: {compiles.n}; device bytes in use "
+    compiles = sum(v["n"] for k, v in telemetry.items()
+                   if k.startswith("compile."))
+    print(f"compiles in the window: {compiles}; device bytes in use "
           f"after freeing the program: "
           f"{(devices[0].memory_stats() or {}).get('bytes_in_use')}",
           file=sys.stderr, flush=True)

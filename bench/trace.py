@@ -5,14 +5,17 @@ reduction runs the same on a recorded ``.xplane.pb`` and on a small JSON
 fixture:
 
     {"devices": {"<device plane>": [[name, start_ns, dur_ns], ...]},
-     "host": [[name, start_ns, dur_ns], ...]}
+     "host": [[name, start_ns, dur_ns], ...],
+     "program": [[name, start_ns, dur_ns], ...]}
 
 ``devices`` holds the operations of each chip's ``XLA Ops`` line, each
 named by its HLO instruction and kind (``%topk_sim.1 custom-call``);
 ``host`` holds the host spans the benchmark records with
 ``jax.profiler.TraceAnnotation`` (``window``, ``plan``,
-``provider.complete``, ``provider.embed``).  Device and host events share
-the profiler's clock.
+``provider.complete``, ``provider.embed``); ``program`` the program's own
+spans (``repro.core.telemetry.SPANS``), kept apart so that the
+reduction below, which reads ``host`` alone, is not changed by them.
+Device and host events share the profiler's clock.
 
 ``reduce`` takes the ``window`` span as the traced window and returns:
 
@@ -35,6 +38,10 @@ import re
 from collections import defaultdict
 
 SPANS = ("window", "plan", "provider.complete", "provider.embed")
+try:
+    from repro.core.telemetry import SPANS as PROGRAM_SPANS
+except ImportError:             # a program without spans of its own
+    PROGRAM_SPANS = ()
 OPS_LINE = "XLA Ops"
 _KIND = re.compile(r"\b([a-z][a-z0-9-]*)\(")
 
@@ -50,7 +57,8 @@ def op_name(hlo: str) -> str:
 
 
 def flatten(profile_dir: str, spans=SPANS) -> dict:
-    """Plain device ops and host spans of the newest ``.xplane.pb``
+    """Plain device ops, the benchmark's host spans named in ``spans``
+    and the program's (``PROGRAM_SPANS``), of the newest ``.xplane.pb``
     under ``profile_dir``."""
     from jax.profiler import ProfileData
 
@@ -59,8 +67,8 @@ def flatten(profile_dir: str, spans=SPANS) -> dict:
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {profile_dir}")
     pd = ProfileData.from_file(files[-1])
-    devices, host, names = {}, [], {}
-    wanted = set(spans)
+    devices, host, prog, names = {}, [], [], {}
+    wanted, ours = set(spans), set(PROGRAM_SPANS)
     for plane in pd.planes:
         if plane.name.startswith("/device:") and "TPU" in plane.name:
             ops = []
@@ -76,9 +84,16 @@ def flatten(profile_dir: str, spans=SPANS) -> dict:
                 devices[plane.name] = ops
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                host.extend([e.name, float(e.start_ns), float(e.duration_ns)]
-                            for e in line.events if e.name in wanted)
-    return {"devices": devices, "host": host}
+                for e in line.events:
+                    if e.name in ours:
+                        out = prog
+                    elif e.name in wanted:
+                        out = host
+                    else:
+                        continue
+                    out.append([e.name, float(e.start_ns),
+                                float(e.duration_ns)])
+    return {"devices": devices, "host": host, "program": prog}
 
 
 def load_json(path: str) -> dict:
