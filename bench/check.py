@@ -48,6 +48,15 @@ def byte_tokens(text: str) -> list[int]:
     return list(text.encode())
 
 
+def pad_rows(rows, length: int) -> np.ndarray:
+    """Token lists to one (B, length) int32 array padded with 0 at the end;
+    causal attention keeps the padding out of every real position."""
+    out = np.zeros((len(rows), length), np.int32)
+    for i, r in enumerate(rows):
+        out[i, :len(r)] = r
+    return out
+
+
 # --------------------------------------------------------------- generation
 def sample_requests(requests, seed: int, max_sequences: int):
     """(prompt, served) token lists of finished requests: the longest,
@@ -67,8 +76,6 @@ def sample_requests(requests, seed: int, max_sequences: int):
 def _teacher_forced(seqs, rows_: int, pad_to: int):
     """Token rows (rows_, pad_to), served-token positions (rows_, G), the
     served tokens (rows_, G) and a validity mask, at fixed shapes."""
-    from bench.reference.dense_transformer import pad_rows
-
     G = max(GEN_CAP, -(-max(len(g) for _, g in seqs) // GEN_CAP) * GEN_CAP)
     rows = [p + g[:-1] for p, g in seqs]
     rows += [[0]] * (rows_ - len(rows))
@@ -184,8 +191,6 @@ def sample_queries(plans, seed: int, n: int):
 
 
 def embed_rows(texts, pad_to: int):
-    from bench.reference.dense_transformer import pad_rows
-
     toks = [byte_tokens(t) for t in texts]
     return pad_rows(toks, pad_to), np.asarray([len(t) for t in toks])
 

@@ -11,6 +11,15 @@ Everything a cell needs is found by name from ``BENCHMARK.json``:
 so a new configuration, traffic mix or metric is a new file and a new
 ``workloads`` entry, with no edit here.
 
+A configuration file holds ``model``, the block as it is served, in
+Hugging Face key names; ``published``, where it is cut from its source,
+the published value of each key it cut or departs from (the experts of
+the whole model where one chip holds its share), which the FLOP count
+and the reference read; and ``smoke_cut``, where its block is not dense:
+``{"model": {...}, "published": {...}}``, its keys at the program's
+smoke preset, which the CPU tests lay over the dense smoke widths
+(``tests/bench/bench_smoke.py``).
+
 The window: plans run back to back from one client (a closed loop)
 until ``seconds`` have passed; every plan started is waited for and
 counted.  Set-up (imports, the model's weights, the corpus and index,
@@ -300,9 +309,12 @@ def passes(checks: dict) -> bool:
 
 # ----------------------------------------------------------- correctness
 def reference_model(config: dict, seed: int, root: Path = ROOT):
+    """The configuration's plain reference (``bench/reference``'s
+    protocol), built from its ``model`` and ``published`` blocks."""
     mod = load_module(root / "bench" / "reference"
                       / f"{config['reference']}.py")
-    return mod.build(config["model"], int(seed) % 2**32)
+    return mod.build(config["model"], int(seed) % 2**32,
+                     config.get("published", {}))
 
 
 def check_run(cell: dict, seed: int, plans, recorded: dict, doc_id,

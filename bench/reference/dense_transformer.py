@@ -20,6 +20,18 @@ Each weight is rounded to the stated ``torch_dtype`` (bfloat16) and then
 held in float32, so the reference sees the values that are served.
 Norms have no learnt parameters at initialisation (unit scale).
 
+``served_weights()`` gives every one of them under the path the program
+stores it at, the ``L`` layers stacked on a leading axis (one stage of
+one block):
+
+    embed                      (V, d)
+    stages/0/b0/attn/wq        (L, d, H, hd)    wk, wv (L, d, KH, hd)
+    stages/0/b0/attn/wo        (L, H, hd, d)
+    stages/0/b0/ffn/w1, w3     (L, d, f)        w2 (L, f, d)
+    stages/0/b0/ln1/scale      (L, d)           ln2 alike; RMSNorm only,
+    final_norm/scale           (d,)             ones; LayerNorm has none
+    lm_head                    (d, V)           untied models only
+
 Layer, per the model's published description:
 
     h  = norm(x);  q, k, v = h Wq, h Wk, h Wv;  rotary positions (the
@@ -42,7 +54,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -78,11 +89,11 @@ class DenseTransformer:
         ks = jax.random.split(jax.random.PRNGKey(self.seed), 8)
         self._top = ks
         key = jax.random.fold_in(ks[2], 0)
-        self.layer_keys = []
+        self._layer_keys = []
         for _ in range(self.L):
             key, sub = jax.random.split(key)
             sub, kr = jax.random.split(sub)
-            self.layer_keys.append(kr)
+            self._layer_keys.append(kr)
         self._layer = jax.jit(self._layer_fn, static_argnames=("mode",))
         self._head = jax.jit(self._head_fn, static_argnames=("mode",))
         self._pool = jax.jit(self._pool_fn)
@@ -93,17 +104,18 @@ class DenseTransformer:
         return w.astype(self.dtype).astype(F32)
 
     def _layer_weights(self, kr):
+        """One layer's weights, keyed by their path in the layer."""
         d, H, KH, hd, f = self.d, self.H, self.KH, self.hd, self.f
         k = jax.random.split(kr, 6)
         a1, a2, a3, a4 = jax.random.split(k[1], 4)
         f1, f2, f3 = jax.random.split(k[3], 3)
-        return {"wq": self._w(a1, (d, H, hd), d ** -0.5),
-                "wk": self._w(a2, (d, KH, hd), d ** -0.5),
-                "wv": self._w(a3, (d, KH, hd), d ** -0.5),
-                "wo": self._w(a4, (H, hd, d), (H * hd) ** -0.5),
-                "w1": self._w(f1, (d, f), d ** -0.5),
-                "w2": self._w(f2, (f, d), f ** -0.5),
-                "w3": self._w(f3, (d, f), d ** -0.5)}
+        return {"attn/wq": self._w(a1, (d, H, hd), d ** -0.5),
+                "attn/wk": self._w(a2, (d, KH, hd), d ** -0.5),
+                "attn/wv": self._w(a3, (d, KH, hd), d ** -0.5),
+                "attn/wo": self._w(a4, (H, hd, d), (H * hd) ** -0.5),
+                "ffn/w1": self._w(f1, (d, f), d ** -0.5),
+                "ffn/w2": self._w(f2, (f, d), f ** -0.5),
+                "ffn/w3": self._w(f3, (d, f), d ** -0.5)}
 
     def embed_table(self):
         return jax.jit(lambda k: self._w(k, (self.V, self.d),
@@ -113,6 +125,21 @@ class DenseTransformer:
         if self.tied:
             return table.T
         return self._w(self._top[3], (self.d, self.V), self.d ** -0.5)
+
+    def served_weights(self) -> dict:
+        """Every served weight by its path (module docstring)."""
+        layers = [self._layer_weights(kr) for kr in self._layer_keys]
+        out = {"embed": self.embed_table()}
+        out.update({f"stages/0/b0/{n}": jnp.stack([w[n] for w in layers])
+                    for n in layers[0]})
+        if self.norm == "rmsnorm":
+            for n in ("ln1", "ln2"):
+                out[f"stages/0/b0/{n}/scale"] = jnp.ones((self.L, self.d),
+                                                         F32)
+            out["final_norm/scale"] = jnp.ones((self.d,), F32)
+        if not self.tied:
+            out["lm_head"] = self._head_weight(None)
+        return out
 
     # ---------------------------------------------------------- layers
     def _norm(self, x):
@@ -143,9 +170,11 @@ class DenseTransformer:
         w = self._layer_weights(kr)
         B, S, _ = x.shape
         h = self._norm(x)
-        q = self._rope(self._mm("bsd,dhk->bshk", h, w["wq"], mode, (0,)))
-        k = self._rope(self._mm("bsd,dhk->bshk", h, w["wk"], mode, (0,)))
-        v = self._mm("bsd,dhk->bshk", h, w["wv"], mode, (0,))
+        q = self._rope(self._mm("bsd,dhk->bshk", h, w["attn/wq"], mode,
+                                (0,)))
+        k = self._rope(self._mm("bsd,dhk->bshk", h, w["attn/wk"], mode,
+                                (0,)))
+        v = self._mm("bsd,dhk->bshk", h, w["attn/wv"], mode, (0,))
         g = self.H // self.KH
         k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
         s = jnp.einsum("bqhk,bshk->bhqs", q, k, precision=HIGHEST)
@@ -154,11 +183,11 @@ class DenseTransformer:
         s = jnp.where(causal[None, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bhqs,bshk->bqhk", p, v, precision=HIGHEST)
-        x = x + self._mm("bshk,hkd->bsd", o, w["wo"], mode, (0, 1))
+        x = x + self._mm("bshk,hkd->bsd", o, w["attn/wo"], mode, (0, 1))
         h = self._norm(x)
-        a = jax.nn.silu(self._mm("bsd,df->bsf", h, w["w1"], mode, (0,)))
-        a = a * self._mm("bsd,df->bsf", h, w["w3"], mode, (0,))
-        return x + self._mm("bsf,fd->bsd", a, w["w2"], mode, (0,))
+        a = jax.nn.silu(self._mm("bsd,df->bsf", h, w["ffn/w1"], mode, (0,)))
+        a = a * self._mm("bsd,df->bsf", h, w["ffn/w3"], mode, (0,))
+        return x + self._mm("bsf,fd->bsd", a, w["ffn/w2"], mode, (0,))
 
     def _head_fn(self, x, at, table, mode="f32"):
         """Logits at positions ``at`` (B, G) of final states x (B, S, d)."""
@@ -179,7 +208,7 @@ class DenseTransformer:
         """Final-layer states (B, S, d) of padded token rows (B, S)."""
         table = self.embed_table() if table is None else table
         x = jnp.take(table, jnp.asarray(tokens, jnp.int32), axis=0)
-        for kr in self.layer_keys:
+        for kr in self._layer_keys:
             x = self._layer(x, kr, mode=mode)
         return x
 
@@ -199,14 +228,7 @@ class DenseTransformer:
         return self._pool(x, jnp.asarray(lengths, jnp.int32))
 
 
-def pad_rows(rows, length: int) -> np.ndarray:
-    """Token lists to one (B, length) int32 array padded with 0 at the end;
-    causal attention keeps the padding out of every real position."""
-    out = np.zeros((len(rows), length), np.int32)
-    for i, r in enumerate(rows):
-        out[i, :len(r)] = r
-    return out
-
-
-def build(model: dict, seed: int) -> DenseTransformer:
+def build(model: dict, seed: int, published: dict) -> DenseTransformer:
+    """``published`` is read by no dense equation: these configurations
+    cut no width, and their ``published`` only records departures."""
     return DenseTransformer(model, seed)

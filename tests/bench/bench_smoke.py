@@ -10,17 +10,43 @@ WAITING = {"phi-3-vision-4.2b.batch_map": ("olmo-1b.batch_map",
                                            "batch_map_16")}
 
 
-def smoke_model(arch: str, model: dict) -> dict:
-    """The configuration's ``model`` block at the program's smoke widths
-    (the served model's own test preset), everything else kept."""
+# widths of a block that is not dense, which the dense smoke keys leave
+# at their published size: a configuration that holds one cuts it itself
+CUT_BY_THE_CONFIGURATION = (
+    "kv_lora_rank", "q_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+    "v_head_dim", "moe_intermediate_size", "n_routed_experts",
+    "n_shared_experts", "num_experts_per_tok", "first_k_dense_replace")
+
+
+def smoke_config(conf: dict) -> dict:
+    """The configuration at the program's smoke widths (the served
+    model's own test preset): the dense keys from the preset, ``head_dim``
+    only where the block has one, then the file's own ``smoke_cut`` over
+    ``model`` and ``published``.  Raises ``ValueError`` naming a width
+    of the block, or of ``published``, that neither sets."""
     from repro.configs import get_smoke_config
 
-    c = get_smoke_config(arch)
-    return dict(model, num_hidden_layers=c.num_layers, hidden_size=c.d_model,
-                num_attention_heads=c.num_heads,
-                num_key_value_heads=c.num_kv_heads,
-                head_dim=c.resolved_head_dim, intermediate_size=c.d_ff,
-                vocab_size=c.vocab_size)
+    c = get_smoke_config(conf["arch"])
+    model = dict(conf["model"], num_hidden_layers=c.num_layers,
+                 hidden_size=c.d_model, num_attention_heads=c.num_heads,
+                 num_key_value_heads=c.num_kv_heads,
+                 intermediate_size=c.d_ff, vocab_size=c.vocab_size)
+    if "head_dim" in model:
+        model["head_dim"] = c.resolved_head_dim
+    cut = conf.get("smoke_cut", {})
+    for block in ("model", "published"):
+        left = [k for k in CUT_BY_THE_CONFIGURATION
+                if conf.get(block, {}).get(k) is not None
+                and k not in cut.get(block, {})]
+        if left:
+            raise ValueError(f"{conf.get('name')}: {block}.{left[0]} is a "
+                             f"width the smoke preset does not set; give it "
+                             f"under smoke_cut.{block}")
+    out = dict(conf, model=dict(model, **cut.get("model", {})))
+    if "published" in conf:
+        out["published"] = dict(conf["published"],
+                                **cut.get("published", {}))
+    return out
 
 
 def make_smoke(cell: dict, rows: int = 3, corpus_rows: int = 2048,
@@ -28,10 +54,10 @@ def make_smoke(cell: dict, rows: int = 3, corpus_rows: int = 2048,
     """A cell as the benchmark loads it, cut to a size the CPU runs in
     seconds: smoke widths, few rows, a small corpus.  Limits are kept."""
     cell = copy.deepcopy(cell)
-    conf, traffic = cell["config"], cell["traffic"]
+    cell["config"] = conf = smoke_config(cell["config"])
+    traffic = cell["traffic"]
     conf["smoke"] = True
     conf["max_context"] = max_context
-    conf["model"] = smoke_model(conf["arch"], conf["model"])
     traffic["rows_per_plan"] = rows
     if "corpus" in traffic:
         traffic["corpus"]["rows"] = corpus_rows

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from bench import harness
-from bench_smoke import smoke_model
+from bench_smoke import smoke_config
 
 CONFIGS = {p.stem: json.loads(p.read_text())
            for p in (harness.ROOT / "bench" / "configs").glob("*.json")}
@@ -21,29 +21,53 @@ def setup(name, seed):
     from repro.configs import get_smoke_config
     from repro.models import model as M
 
-    conf = dict(CONFIGS[name])
-    conf["model"] = smoke_model(conf["arch"], conf["model"])
+    conf = smoke_config(CONFIGS[name])
     cfg = get_smoke_config(conf["arch"])
     params = M.init_params(cfg, jax.random.PRNGKey(seed % 2**32))
     return conf, cfg, params, harness.reference_model(conf, seed)
 
 
+def served_leaves(params) -> dict:
+    """The program's parameter tree as ``{"/"-joined path: leaf}``."""
+    def name(k):
+        return str(k.key if hasattr(k, "key") else k.idx)
+
+    return {"/".join(name(k) for k in path): leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(params)[0]}
+
+
+def assert_served_weights(ref, params):
+    """Every served leaf, and nothing else, is the reference's, bit for
+    bit once both are held in float32."""
+    got = ref.served_weights()
+    want = served_leaves(params)
+    assert set(got) == set(want), (sorted(set(want) - set(got)),
+                                   sorted(set(got) - set(want)))
+    for k, w in want.items():
+        assert np.array_equal(np.asarray(got[k], np.float32),
+                              np.asarray(w, np.float32)), k
+
+
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_reference_weights_are_the_served_weights(name):
     conf, cfg, params, ref = setup(name, 2**31 + 41)
-    f32 = np.float32
-    assert np.array_equal(np.asarray(ref.embed_table()),
-                          np.asarray(params["embed"], f32))
-    stage = params["stages"][0]
-    for r, kr in enumerate(ref.layer_keys):
-        w = ref._layer_weights(kr)
-        layer = jax.tree.map(lambda a: a[r], stage)["b0"]
-        for n in ("wq", "wk", "wv", "wo"):
-            assert np.array_equal(np.asarray(w[n]),
-                                  np.asarray(layer["attn"][n], f32)), n
-        for n in ("w1", "w2", "w3"):
-            assert np.array_equal(np.asarray(w[n]),
-                                  np.asarray(layer["ffn"][n], f32)), n
+    assert_served_weights(ref, params)
+
+
+@pytest.mark.parametrize("fault", ["dropped", "extra", "altered"])
+def test_the_weight_comparison_fails_a_reference_that_differs(
+        fault, monkeypatch):
+    conf, cfg, params, ref = setup("phi-3-vision-4.2b", 2**31 + 43)
+    weights = ref.served_weights()
+    if fault == "dropped":
+        del weights["stages/0/b0/ln2/scale"]
+    elif fault == "extra":
+        weights["stages/1/b0/attn/wq"] = weights["stages/0/b0/attn/wq"]
+    else:
+        weights["lm_head"] = weights["lm_head"].at[0, 0].add(1.0)
+    monkeypatch.setattr(ref, "served_weights", lambda: weights)
+    with pytest.raises(AssertionError):
+        assert_served_weights(ref, params)
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
